@@ -328,7 +328,7 @@ def _leakage_canary() -> None:
     k = jnp.asarray(rng.normal(size=(2, 192, 32)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(2, 192, 32)), jnp.float32)
     kw = dict(cfg=RaggedPrefillConfig(block_q=32, block_kv=32),
-              interpret=jax.default_backend() != "tpu")
+              interpret=jax.default_backend() == "cpu")
     clean = np.asarray(ragged_prefill_attend(
         q, k, v, seg, pos, seg, pos, **kw))
     k2, v2 = np.asarray(k).copy(), np.asarray(v).copy()

@@ -48,7 +48,7 @@ from .. import dsl
 from ..costs import (CostEstimate, HBM_BW, PEAK_FLOPS, occupancy,
                      sol_estimate)
 from ..kernelspec import (DTYPE_BYTES, StructuralIssue, check_alignment,
-                          check_vmem)
+                          check_block_shapes, check_vmem)
 from ..tags import Expr, app, make_tag
 from .base import (BugSignature, KernelFamily, generic_skill,
                    register)
@@ -271,9 +271,35 @@ def build_ragged_prefill_program(cfg: RaggedPrefillConfig,
     return p
 
 
+def kernel_blocks(cfg: RaggedPrefillConfig, *, total_q: int, total_k: int,
+                  q_heads: int, kv_heads: int, head_dim: int):
+    """operand -> (block shape, array shape) for every operand the
+    Pallas kernel tiles: the one layout :mod:`repro.kernels
+    .ragged_prefill` builds its BlockSpecs from and the structural gate
+    checks against the TPU compiler's block rule.  The per-token
+    metadata rides as a (TQ, 1) column tiled (block_q, 1) on the query
+    side and as (TK / block_kv, 1, block_kv) rows tiled (1, 1, block_kv)
+    on the kv side: the kernel reads them as the (bq, 1) and (1, bkv)
+    operands of its mask with no relayout, and the kv block's trailing
+    pair equals the array's for every block_kv."""
+    bq, bkv, D = cfg.block_q, cfg.block_kv, head_dim
+    q = ((1, bq, D), (q_heads, total_q, D))
+    kv = ((1, bkv, D), (kv_heads, total_k, D))
+    q_meta = ((bq, 1), (total_q, 1))
+    kv_meta = ((1, 1, bkv), (total_k // bkv, 1, bkv))
+    return {"Q": q, "K": kv, "V": kv, "O": q,
+            "seg_q": q_meta, "pos_q": q_meta,
+            "seg_k": kv_meta, "pos_k": kv_meta}
+
+
 def structural_ragged_prefill(cfg: RaggedPrefillConfig,
                               prob: RaggedPrefillProblem):
     issues = []
+    # the family models packed self-attention: one token axis for both
+    issues += check_block_shapes(kernel_blocks(
+        cfg, total_q=prob.total_tokens, total_k=prob.total_tokens,
+        q_heads=prob.q_heads, kv_heads=prob.kv_heads,
+        head_dim=prob.head_dim))
     if prob.total_tokens % cfg.block_q or prob.total_tokens % cfg.block_kv:
         issues.append(StructuralIssue(
             "masking", f"blocks ({cfg.block_q}, {cfg.block_kv}) do not "

@@ -45,6 +45,27 @@ def cdiv(a: int, b: int) -> int:
 class StructuralIssue:
     kind: str
     message: str
+    hard: bool = False      # the chip's compiler refuses it: rejects
+
+
+def check_block_shapes(blocks: Dict[str, Tuple[Sequence[int],
+                                               Sequence[int]]]
+                       ) -> List[StructuralIssue]:
+    """Mosaic's BlockSpec rule, as the Pallas TPU lowering enforces it:
+    the last two dims of every block are divisible by (8, 128) or equal
+    to the array's.  ``blocks`` maps operand -> (block shape, array
+    shape).  The compiler refuses anything else, so each issue is hard."""
+    issues: List[StructuralIssue] = []
+    for name, (block, array) in blocks.items():
+        bs, arr = (1, 1, *block)[-2:], (1, 1, *array)[-2:]
+        if not all(b == a or b % q == 0
+                   for b, a, q in zip(bs, arr, (8, LANE))):
+            issues.append(StructuralIssue(
+                "block_shape",
+                f"{name}: block {tuple(block)} of array {tuple(array)} — "
+                f"the trailing pair must be divisible by (8, {LANE}) or "
+                f"equal the array's", hard=True))
+    return issues
 
 
 def check_alignment(name: str, block_shape: Sequence[int], dtype: str,
@@ -124,9 +145,11 @@ class VerifyResult:
 
     @property
     def hard_ok(self) -> bool:
-        """Data-flow invariants only (structural issues are perf warnings in
-        some contexts, e.g. alignment on edge blocks)."""
-        return self.report is None or self.report.ok
+        """Data-flow invariants plus the structural issues the compiler
+        would refuse (the others are perf warnings in some contexts,
+        e.g. alignment on edge blocks)."""
+        return ((self.report is None or self.report.ok)
+                and not any(s.hard for s in self.structural))
 
     def render(self) -> str:
         lines = []

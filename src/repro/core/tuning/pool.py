@@ -316,8 +316,20 @@ class WorkerPool:
                               str(trace_dir) if trace_dir else None),
                         daemon=True, name=f"fleet-worker-{i}")
             for i in range(workers)]
-        for p in self.procs:
-            p.start()
+        # a worker's kernel runs are the families' interpret-mode oracle
+        # checks: pin it to the CPU before it imports JAX, so no worker
+        # takes the chip from a parent that holds it (spawned children
+        # start with the parent's environment of the moment)
+        prev = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            for p in self.procs:
+                p.start()
+        finally:
+            if prev is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = prev
 
     @property
     def pending(self) -> int:

@@ -843,6 +843,9 @@ _STRUCT_HINTS = {
             "fits the per-core VMEM budget",
     "masking": "declare the non-divisible dim masked or pick a divisible "
                "block size",
+    "block_shape": "pick blocks whose trailing pair is divisible by "
+                   "(8, 128) or equals the array's — the TPU compiler "
+                   "refuses any other",
 }
 
 

@@ -21,8 +21,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.invariants import FlashDecodeConfig
 
-from .._compat import CompilerParams
-
 NEG_INF = -1e30
 F32 = jnp.float32
 
@@ -98,7 +96,7 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             jax.ShapeDtypeStruct((B * Hq, ns, 1), F32),
             jax.ShapeDtypeStruct((B * Hq, ns, 1), F32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(qf, kf, vf, kvl)

@@ -24,8 +24,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.invariants import FlashAttentionConfig
 
-from .._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -141,7 +139,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

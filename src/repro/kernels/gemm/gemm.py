@@ -23,8 +23,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.invariants import GemmConfig
 
-from .._compat import CompilerParams
-
 
 def make_kernel(nk: int, n_axes: int):
     """Build the kernel body for an ``n_axes``-dim grid whose last axis is
@@ -126,7 +124,7 @@ def gemm(a: jnp.ndarray, b: jnp.ndarray, *, cfg: GemmConfig = GemmConfig(),
         out_specs=pl.BlockSpec((bm, bn), o_idx),
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(dimension_semantics=sem),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=sem),
         interpret=interpret,
     )(a, b)
 

@@ -29,8 +29,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.invariants import MoEConfig
 
-from .._compat import CompilerParams
-
 
 def _silu(x):
     return x / (1.0 + jnp.exp(-x))
@@ -90,7 +88,7 @@ def grouped_ffn(x_routed: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
         out_specs=pl.BlockSpec((1, bt, DM), lambda e, t, f: (e, t, 0)),
         out_shape=jax.ShapeDtypeStruct((E, C, DM), x_routed.dtype),
         scratch_shapes=[pltpu.VMEM((bt, DM), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_routed, wg, wu, wd, gates_routed)

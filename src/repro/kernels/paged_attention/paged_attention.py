@@ -35,7 +35,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.families.paged_attention import PagedAttentionConfig
-from .._compat import CompilerParams
 
 NEG_INF = -1e30
 F32 = jnp.float32
@@ -138,7 +137,7 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
                           q_heads=Hq, page_size=PS),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, 1, D), F32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(tflat, lens, qf, k_pages, v_pages)

@@ -20,7 +20,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.families.quant_gemm import QuantGemmConfig
-from .._compat import CompilerParams
 
 
 def make_kernel(nk: int):
@@ -88,7 +87,7 @@ def quant_gemm(a: jnp.ndarray, b: jnp.ndarray, sa: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, sa, sb)

@@ -3,8 +3,11 @@
 Queries and KV both live at *packed* offsets; the per-token metadata
 (``seg`` = owning segment, ``pos`` = segment-relative position, derived
 from cu_seqlens by :mod:`.packing`) rides in as VMEM blocks alongside
-the tiles they describe.  The segment/causal mask is applied **before**
-the online softmax:
+the tiles they describe, in the layout
+:func:`repro.core.families.ragged_prefill.kernel_blocks` fixes (a
+(block_q, 1) column per query block, a (1, block_kv) row per kv block —
+legal TPU blocks for every config the gate admits).  The segment/causal
+mask is applied **before** the online softmax:
 
     admit(q, k)  ⇔  seg_q == seg_k  ∧  pos_k <= pos_q  ∧  both >= 0
 
@@ -30,9 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.families.ragged_prefill import RaggedPrefillConfig
-
-from .._compat import CompilerParams
+from repro.core.families.ragged_prefill import (RaggedPrefillConfig,
+                                                kernel_blocks)
 
 NEG_INF = -1e30
 F32 = jnp.float32
@@ -58,10 +60,10 @@ def _ragged_kernel(q_ref, k_ref, v_ref, sq_ref, pq_ref, sk_ref, pk_ref,
     # the leakage mask: same segment, causally visible, not padding —
     # applied BEFORE the online softmax so foreign-sequence and padding
     # scores never touch the (m, l, acc) carry
-    sq = sq_ref[0][:, None]                        # (bq, 1)
-    pq = pq_ref[0][:, None]
-    sk = sk_ref[0][None, :]                        # (1, bkv)
-    pk = pk_ref[0][None, :]
+    sq = sq_ref[...]                               # (bq, 1)
+    pq = pq_ref[...]
+    sk = sk_ref[0]                                 # (1, bkv)
+    pk = pk_ref[0]
     mask = (sq == sk) & (pk <= pq) & (sq >= 0) & (sk >= 0)
     s = jnp.where(mask, s, NEG_INF)
 
@@ -103,11 +105,11 @@ def ragged_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             f"blocks ({bq}, {bkv}) must tile the packed buffers "
             f"(TQ={TQ}, TK={TK}) — pad before packing")
     scale = float(scale if scale is not None else D ** -0.5)
-
-    sq = seg_q.reshape(1, TQ).astype(jnp.int32)
-    pq = pos_q.reshape(1, TQ).astype(jnp.int32)
-    sk = seg_k.reshape(1, TK).astype(jnp.int32)
-    pk = pos_k.reshape(1, TK).astype(jnp.int32)
+    blk = kernel_blocks(cfg, total_q=TQ, total_k=TK, q_heads=Hq,
+                        kv_heads=Hkv, head_dim=D)
+    meta = [m.reshape(blk[name][1]).astype(jnp.int32)
+            for name, m in (("seg_q", seg_q), ("pos_q", pos_q),
+                            ("seg_k", seg_k), ("pos_k", pos_k))]
     nq, nk = TQ // bq, TK // bkv
 
     def q_idx(h, qb, kb):
@@ -121,23 +123,23 @@ def ragged_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         functools.partial(_ragged_kernel, n_steps=nk, scale=scale),
         grid=(Hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, D), q_idx),
-            pl.BlockSpec((1, bkv, D), kv_idx),
-            pl.BlockSpec((1, bkv, D), kv_idx),
-            pl.BlockSpec((1, bq), lambda h, qb, kb: (0, qb)),
-            pl.BlockSpec((1, bq), lambda h, qb, kb: (0, qb)),
-            pl.BlockSpec((1, bkv), lambda h, qb, kb: (0, kb)),
-            pl.BlockSpec((1, bkv), lambda h, qb, kb: (0, kb)),
+            pl.BlockSpec(blk["Q"][0], q_idx),
+            pl.BlockSpec(blk["K"][0], kv_idx),
+            pl.BlockSpec(blk["V"][0], kv_idx),
+            pl.BlockSpec(blk["seg_q"][0], lambda h, qb, kb: (qb, 0)),
+            pl.BlockSpec(blk["pos_q"][0], lambda h, qb, kb: (qb, 0)),
+            pl.BlockSpec(blk["seg_k"][0], lambda h, qb, kb: (kb, 0, 0)),
+            pl.BlockSpec(blk["pos_k"][0], lambda h, qb, kb: (kb, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, D), q_idx),
+        out_specs=pl.BlockSpec(blk["O"][0], q_idx),
         out_shape=jax.ShapeDtypeStruct((Hq, TQ, D), F32),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), F32),
             pltpu.VMEM((bq, 1), F32),
             pltpu.VMEM((bq, D), F32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, sq, pq, sk, pk)
+    )(q, k, v, *meta)
     return out.astype(q.dtype)

@@ -17,7 +17,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.invariants import SSDConfig
 
-from .._compat import CompilerParams
 
 F32 = jnp.float32
 
@@ -87,7 +86,7 @@ def ssd_chunk_scan(x: jnp.ndarray, da: jnp.ndarray, Bm: jnp.ndarray,
         out_specs=pl.BlockSpec((1, q, P), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), F32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, da, Bm, Cm)

@@ -5,10 +5,17 @@
 
 Loads the latest checkpoint when present (otherwise fresh init), spins the
 chosen engine — ``--engine paged`` (default) runs the block-table KV-pool
-engine with chunked prefill and headroom admission; ``--engine dense``
-the per-slot slab baseline — and reports completion, throughput and the
-engine's metrics snapshot. The decode_32k / long_500k dry-run cells
-exercise the same serve_step at production shapes.
+engine with chunked prefill and headroom admission, decode through the
+paged-attention kernel and prefill through the ragged-prefill kernel;
+``--engine dense`` the per-slot slab baseline — and reports completion,
+throughput and the engine's metrics snapshot.  :func:`build_engine` is
+the setup both :func:`main` and ``chip_smoke.py`` run.  The decode_32k /
+long_500k dry-run cells exercise the same serve_step at production
+shapes.
+
+JAX's persistent compilation cache (:func:`use_compile_cache`): where
+``JAX_COMPILATION_CACHE_DIR`` is set JAX keeps its cache there; otherwise
+the entry points point it at ``<checkout>/.jax_cache``.
 
 Observability (docs/observability.md): ``--metrics-port N`` serves the
 live metrics snapshot in Prometheus text format at
@@ -20,7 +27,9 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -30,8 +39,23 @@ from repro.checkpoint import CheckpointManager
 from repro.models import build
 from repro.serve import PagedServingEngine, Request, ServingEngine
 
+# fixed, inside the checkout and git-ignored: the cache directory is
+# part of the cache key, so a path that moved between runs never hits
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def main(argv=None):
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; call from an entry
+    point before anything compiles.  ``JAX_COMPILATION_CACHE_DIR``, when
+    set, is JAX's own setting and wins untouched."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
+
+
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -58,15 +82,20 @@ def main(argv=None):
     ap.add_argument("--trace-out", default=None,
                     help="enable span tracing; dump the Perfetto trace "
                          "file here on shutdown")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def build_engine(args):
+    """Model (``--seed`` init, or the latest checkpoint) and the chosen
+    serving engine, from parsed :func:`parser` arguments."""
     cfg = (configs.get_reduced(args.arch) if args.reduced
            else configs.get_config(args.arch))
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
-    ckpt_dir = args.ckpt_dir or f"checkpoints/{cfg.name}"
-    mgr = CheckpointManager(ckpt_dir)
-    if mgr.latest_step() is not None:
+    ckpt_dir = Path(args.ckpt_dir or f"checkpoints/{cfg.name}")
+    # look, don't create: CheckpointManager makes its directory
+    mgr = CheckpointManager(ckpt_dir) if ckpt_dir.is_dir() else None
+    if mgr is not None and mgr.latest_step() is not None:
         state = mgr.restore({"params": model.abstract()})
         params = state["params"]
         print(f"restored step {state['meta']['step']} from {ckpt_dir}")
@@ -77,18 +106,25 @@ def main(argv=None):
         table = load_dispatch_table(args.dispatch_table)
         print(f"dispatch table: {table.summary()}")
 
-    if args.engine == "paged":
-        pool_pages = args.pool_pages or max(
-            2, args.slots * args.max_len * 3 // (4 * args.page_size))
-        eng = PagedServingEngine(
-            model, params, pool_pages=pool_pages,
-            page_size=args.page_size, max_batch=args.slots,
-            max_len=args.max_len, prefill_chunk=args.prefill_chunk,
-            eos_id=-1, dispatch_table=table)
-    else:
-        eng = ServingEngine(model, params, n_slots=args.slots,
-                            max_len=args.max_len, eos_id=-1,
-                            dispatch_table=table)
+    if args.engine == "dense":
+        return ServingEngine(model, params, n_slots=args.slots,
+                             max_len=args.max_len, eos_id=-1,
+                             dispatch_table=table)
+    pool_pages = args.pool_pages or max(
+        2, args.slots * args.max_len * 3 // (4 * args.page_size))
+    return PagedServingEngine(
+        model, params, pool_pages=pool_pages,
+        page_size=args.page_size, max_batch=args.slots,
+        max_len=args.max_len, prefill_chunk=args.prefill_chunk,
+        eos_id=-1, dispatch_table=table, decode_path="kernel",
+        prefill_path="kernel")
+
+
+def main(argv=None):
+    args = parser().parse_args(argv)
+    use_compile_cache()
+    eng = build_engine(args)
+    vocab = eng.model.cfg.vocab
     if args.trace_out:
         obs.enable()
     server = None
@@ -103,7 +139,7 @@ def main(argv=None):
     for rid in range(args.requests):
         plen = int(rng.integers(4, args.max_len // 4))
         eng.submit(Request(
-            rid, rng.integers(2, cfg.vocab, size=plen).tolist(),
+            rid, rng.integers(2, vocab, size=plen).tolist(),
             max_new_tokens=args.max_new_tokens))
 
     t0 = time.perf_counter()

@@ -32,7 +32,10 @@ counter stays at 0 on decode ticks).  The kernel config is resolved per
 shape bucket from the installed dispatch table and statically verified
 once per batch geometry; when no verified config exists for the bucket
 (or the model's cache cannot be paged-attended, e.g. MLA) the tick
-falls back to the gather path.  Per-sequence ``lengths`` (the token
+falls back to the gather path, and the metrics show it: the tick adds
+a dense view to ``gather_bytes`` and nothing to ``kernel_decode_ticks``
+(prefill alike, with ``prefill_gather_bytes`` and
+``kernel_prefill_ticks``).  Per-sequence ``lengths`` (the token
 being written included) are re-validated against each row's mapped page
 count every kernel tick — the boundary-page consistency check on the
 hot path.
@@ -302,14 +305,20 @@ class PagedServingEngine:
         self._admission_stamp = 0
         self._next_seq_id = 0
         self._table_sig = None
-        # kernel decode path: config verified per batch geometry, pallas
-        # interpret mode off the TPU, dense-view bytes for the gather-
-        # path HBM accounting
+        # kernel decode path: config verified per batch geometry, dense-
+        # view bytes for the gather-path HBM accounting.  The Pallas
+        # kernels lower for the TPU and run interpreted on the CPU (tests,
+        # reduced smokes); no other backend may silently interpret them
+        backend = jax.default_backend()
+        if "kernel" in (decode_path, prefill_path) \
+                and backend not in ("cpu", "tpu"):
+            raise ValueError(f"kernel paths run on the TPU (interpreted "
+                             f"on the CPU), not on backend {backend!r}")
+        self._interpret = backend == "cpu"
         self.decode_path = decode_path
         self._kernel_sig = None
         self._kernel_cfg = None
         self._kernel_fn = None
-        self._interpret = jax.default_backend() != "tpu"
         self._view_bytes = KVPool.dense_reserved_bytes(
             model, max_batch, max_len)
         # kernel prefill path: verified config + jit closure memoized per
@@ -547,8 +556,9 @@ class PagedServingEngine:
         # pad both packed extents to 64-token granularity: bounds the
         # jit-recompile variety while keeping pow2 blocks available
         # (64 is itself a valid block size, so every padded extent
-        # tiles) and the packed read below the dense batch view at
-        # small shapes
+        # tiles, with blocks the TPU compiler accepts — the gate checks
+        # them) and the packed read below the dense batch view at small
+        # shapes
         pad = lambda t: -(-max(t, 1) // 64) * 64
         TQp = pad(sum(n for *_, n in spans))
         TKp = pad(sum(p + n for _, _, p, n in spans))
@@ -670,6 +680,26 @@ class PagedServingEngine:
             jnp.asarray(tokens), jnp.asarray(pos_vec),
             jnp.asarray(lengths))
         return logits
+
+    def lower_kernel_steps(self) -> Dict[str, "jax.stages.Lowered"]:
+        """Lower every kernel-path step this engine has built, at the
+        shapes it ran: ``decode`` and one ``prefill_<TQ>x<TK>_<n>`` per
+        packed geometry.  ``.compile()`` on each gives the program the
+        device runs: its ``as_text()`` (on the TPU the Pallas kernels
+        show as ``tpu_custom_call``) and its ``memory_analysis()``."""
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        B, NP = self.max_batch, self.pages_per_seq
+        out = {}
+        if self._kernel_fn is not None:
+            out["decode"] = self._kernel_fn.lower(
+                self.params, self.kv.storage, i32(B, NP), i32(B, 1),
+                i32(B), i32(B))
+        for (tq, tk, n), fn in sorted(self._prefill_fns.items()):
+            out[f"prefill_{tq}x{tk}_{n}"] = fn.lower(
+                self.params, self.kv.storage, i32(1, tq), i32(tq),
+                i32(tq), i32(tk), i32(tk), i32(tq), i32(tq), i32(tk),
+                i32(tk))
+        return out
 
     def _decode_tick(self) -> Dict[str, int]:
         rows = [(i, s) for i, s in enumerate(self.rows)

@@ -92,6 +92,36 @@ class TestLeakageInvariants:
         assert any(s.kind == "masking"
                    for s in FAM.structural(CFG, ragged))
 
+    @pytest.mark.parametrize("bq,bkv", [(4, 64), (64, 4), (64, 12)])
+    def test_blocks_the_tpu_compiler_refuses_are_hard_issues(self, bq,
+                                                             bkv):
+        """Every tiled operand, the seg/pos metadata included, must
+        have a trailing block pair divisible by (8, 128) or equal to
+        the array's: the gate rejects what Mosaic would refuse."""
+        from repro.kernels.ragged_prefill import verified_config
+        cfg = FAM.config_cls(block_q=bq, block_kv=bkv)
+        issues = [s for s in FAM.structural(cfg, FAM.problem_cls(
+            2, 192, 16, 8, 128)) if s.hard]
+        assert issues and all(s.kind == "block_shape" for s in issues)
+        assert verified_config(64, 192, 2, q_heads=16, kv_heads=8,
+                               head_dim=128, cfg=cfg) is None
+
+    @pytest.mark.parametrize("tq,tk", [(64, 64), (64, 192), (192, 448),
+                                       (256, 512), (320, 1216)])
+    def test_engine_extents_get_a_legal_verified_config(self, tq, tk):
+        """The serving engine pads both packed extents to 64 tokens;
+        the config the gate picks for each has only legal blocks (the
+        compile side of this is tests/test_tpu_compile.py)."""
+        from repro.core.families.ragged_prefill import kernel_blocks
+        from repro.core.kernelspec import check_block_shapes
+        from repro.kernels.ragged_prefill import verified_config
+        cfg = verified_config(tq, tk, 2, q_heads=16, kv_heads=8,
+                              head_dim=128)
+        assert cfg is not None
+        assert check_block_shapes(kernel_blocks(
+            cfg, total_q=tq, total_k=tk, q_heads=16, kv_heads=8,
+            head_dim=128)) == []
+
     def test_blocks_must_tile_the_packed_buffer(self):
         eng = VerificationEngine()
         res = eng.verify("ragged_prefill",

@@ -112,11 +112,7 @@ class TestFaultTolerance:
 class TestShardingRules:
     def _mesh(self):
         from jax.sharding import AbstractMesh
-        try:
-            return AbstractMesh((16, 16), ("data", "model"))
-        except TypeError:
-            # jax 0.4.x spelling: one tuple of (axis name, size) pairs
-            return AbstractMesh((("data", 16), ("model", 16)))
+        return AbstractMesh((16, 16), ("data", "model"))
 
     def test_divisibility_fallback(self):
         mesh = self._mesh()
