@@ -18,6 +18,14 @@ example (the dict below is embedded verbatim in
 
 The clock is injectable (seconds, monotonic); benchmarks pass a
 :class:`TickClock` so two runs emit byte-identical trace files.
+
+Every enabled span also opens a ``jax.profiler.TraceAnnotation`` of
+the same name, so while a JAX profiler trace is being collected the
+span sits on the trace's host plane, on the same clock as the device
+ops; with no profiler session collecting, the annotation costs next to
+nothing.  Scalar attrs become the annotation's metadata.  JAX is
+imported when :func:`enable` makes a tracer, so the module stays
+stdlib-only at import.
 """
 import json
 import os
@@ -63,7 +71,7 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """Live span: stamps start on entry, appends one event on exit."""
 
-    __slots__ = ("_tracer", "name", "tid", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "tid", "attrs", "_t0", "_ann")
 
     def __init__(self, tracer, name, tid, attrs):
         self._tracer = tracer
@@ -80,6 +88,8 @@ class _Span:
         return self
 
     def __enter__(self):
+        self._ann = self._tracer._annotation(self.name)
+        self._ann.__enter__()
         self._t0 = self._tracer._now_us()
         return self
 
@@ -87,6 +97,12 @@ class _Span:
         tr = self._tracer
         tr._events.append((self.name, self._t0,
                            tr._now_us() - self._t0, self.tid, self.attrs))
+        if self.attrs:
+            meta = {k: v for k, v in self.attrs.items()
+                    if isinstance(v, (bool, int, float, str))}
+            if meta:
+                self._ann.set_metadata(**meta)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -96,16 +112,19 @@ class Tracer:
     ``clock`` returns seconds (monotonic); timestamps are microseconds
     relative to the clock value captured at construction, so traces
     start near ``ts == 0``.  ``pid`` labels the process lane in the
-    exported file (the fleet uses worker ids).
+    exported file (the fleet uses worker ids).  Every span also opens a
+    ``jax.profiler.TraceAnnotation``.
     """
 
     def __init__(self, clock: Optional[Callable[[], float]] = None, *,
                  capacity: int = _DEFAULT_CAPACITY, pid: Optional[int] = None):
         import time
+        from jax.profiler import TraceAnnotation
         self._clock = clock or time.perf_counter
         self._epoch = self._clock()
         self._events: deque = deque(maxlen=capacity)
         self.pid = os.getpid() if pid is None else pid
+        self._annotation = TraceAnnotation
 
     def _now_us(self) -> int:
         return int((self._clock() - self._epoch) * 1e6)

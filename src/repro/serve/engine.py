@@ -100,7 +100,23 @@ class _Slot:
     pos: int = 0          # next write offset in the cache
 
 
-class ServingEngine:
+class _HostReads:
+    """The engines' one way to read a device value back to the host:
+    each read blocks until the device has computed it, and counts
+    toward the tick's ``host_syncs``."""
+
+    _host_syncs = 0
+
+    def _to_host(self, x) -> int:
+        self._host_syncs += 1
+        return int(x)
+
+    def _take_host_syncs(self) -> int:
+        n, self._host_syncs = self._host_syncs, 0
+        return n
+
+
+class ServingEngine(_HostReads):
     """Dense-slab slot engine (the paged engine's token oracle)."""
 
     def __init__(self, model, params, *, n_slots: int = 4,
@@ -164,7 +180,7 @@ class ServingEngine:
             toks = jnp.asarray([req.prompt], jnp.int32)
             logits, cache1 = self._prefill(self.params, toks)
             self._insert_cache(i, cache1)
-            nxt = int(jnp.argmax(logits[0, -1]))
+            nxt = self._to_host(jnp.argmax(logits[0, -1]))
             req.output.append(nxt)
             s.req, s.pos = req, len(req.prompt)
             admitted += 1
@@ -199,7 +215,7 @@ class ServingEngine:
                 jnp.asarray(pos_vec))
             for i in active:
                 s = self.slots[i]
-                nxt = int(jnp.argmax(logits[i, -1]))
+                nxt = self._to_host(jnp.argmax(logits[i, -1]))
                 s.req.output.append(nxt)
                 s.pos += 1
                 lat = self._lat.get(s.req.rid)
@@ -224,6 +240,7 @@ class ServingEngine:
         self.metrics.record_tick(
             queue_depth=len(self.queue), active=occ, occupancy=occ,
             decode_tokens=len(active), finished=finished,
+            host_syncs=self._take_host_syncs(),
             step_time_us=int((self._clock() - t0) * 1e6), **adm)
         return len(active)
 
@@ -249,7 +266,7 @@ class _Seq:
     resumed: bool = False     # re-admitted after a preemption
 
 
-class PagedServingEngine:
+class PagedServingEngine(_HostReads):
     """Paged continuous batching over a shared block-table KV pool.
 
     ``max_batch`` bounds the decode call's width (a jit shape, not a
@@ -305,6 +322,7 @@ class PagedServingEngine:
         self._admission_stamp = 0
         self._next_seq_id = 0
         self._table_sig = None
+        self._verify_us = 0      # this tick's verification time (metrics)
         # kernel decode path: config verified per batch geometry, dense-
         # view bytes for the gather-path HBM accounting.  The Pallas
         # kernels lower for the TPU and run interpreted on the CPU (tests,
@@ -432,25 +450,39 @@ class PagedServingEngine:
                                             self.pages_per_seq)
         return t
 
+    def _verify(self, gate, *args, **kwargs):
+        """Run an ARGUS gate on a geometry not met before, inside a
+        ``serve.verify`` span; its time on the engine's clock goes to
+        the tick's ``verify_us``."""
+        t0 = self._clock()
+        try:
+            with _obs.span("serve.verify"):
+                return gate(*args, **kwargs)
+        finally:
+            self._verify_us += round((self._clock() - t0) * 1e6)
+
     def _gather(self) -> Dict:
-        tables = self._tables()
-        sig = (tables.shape, self.alloc.n_pages)
-        if sig != self._table_sig:
-            # ARGUS gate: verify the paged_attention family's indirection
-            # invariants for this batch geometry (config resolved from the
-            # installed dispatch table) before the gather consumes it
-            from repro.kernels.paged_attention.ops import \
-                validate_block_tables
-            validate_block_tables(
-                tables, model=self.model, page_size=self.page_size,
-                pool_pages=self.alloc.n_pages)
-            self._table_sig = sig
-        else:
-            # geometry already verified: still range-check the concrete
-            # mapping (the runtime mirror of assert_in_range)
-            if tables.min() < 0 or tables.max() >= self.alloc.n_pages:
-                raise ValueError("block table maps outside the pool")
-        return self.kv.gather(jnp.asarray(tables))
+        with _obs.span("serve.tables"):
+            tables = self._tables()
+            sig = (tables.shape, self.alloc.n_pages)
+            if sig != self._table_sig:
+                # ARGUS gate: verify the paged_attention family's
+                # indirection invariants for this batch geometry (config
+                # resolved from the installed dispatch table) before the
+                # gather consumes it
+                from repro.kernels.paged_attention.ops import \
+                    validate_block_tables
+                self._verify(
+                    validate_block_tables, tables, model=self.model,
+                    page_size=self.page_size, pool_pages=self.alloc.n_pages)
+                self._table_sig = sig
+            else:
+                # geometry already verified: still range-check the
+                # concrete mapping (the runtime mirror of assert_in_range)
+                if tables.min() < 0 or tables.max() >= self.alloc.n_pages:
+                    raise ValueError("block table maps outside the pool")
+        with _obs.span("serve.launch"):
+            return self.kv.gather(jnp.asarray(tables))
 
     # -- prefill -------------------------------------------------------------
     def _prefill_tick(self) -> Dict[str, int]:
@@ -490,48 +522,51 @@ class PagedServingEngine:
                 pos_vec[i] = s.pos
             view = self._gather()
             fn = self._chunk if self._chunk is not None else self._decode
-            logits, view = fn(self.params, view, jnp.asarray(tokens),
-                              jnp.asarray(pos_vec))
-            self._scatter(view, {i: (s.pos, lens[i]) for i, s in pend})
+            with _obs.span("serve.launch"):
+                logits, view = fn(self.params, view, jnp.asarray(tokens),
+                                  jnp.asarray(pos_vec))
+                self._scatter(view, {i: (s.pos, lens[i]) for i, s in pend})
             row_logits = {i: logits[i, lens[i] - 1] for i, s in pend}
             gather_bytes = self._view_bytes
         total = 0
         finished = 0
         tick = self.metrics.counters["ticks"]
-        for i, s in pend:
-            s.pos += lens[i]
-            total += lens[i]
-            if s.pos == len(s.ctx):
-                # prompt complete: first generated token comes from the
-                # logits at the chunk's last real position (the dense
-                # engine's argmax(prefill_logits[-1]) twin)
-                nxt = int(jnp.argmax(row_logits[i]))
-                s.req.output.append(nxt)
-                s.prefilled = True
-                lat = self._lat.get(s.req.rid)
-                if lat is not None:
-                    if "last" not in lat:
-                        # first token ever for this request: TTFT
-                        self.metrics.record_latency(
-                            "ttft", tick - lat.get("submit", tick))
-                    else:
-                        # resumed prefill replays a decode tick: TPOT
-                        self.metrics.record_latency(
-                            "tpot", tick - lat["last"])
-                    lat["last"] = tick
-                # a *resumed* prefill replays a decode tick, so its token
-                # gets the decode-tick exhaustion check (fresh admissions
-                # mirror the dense engine, which checks only on decode)
-                if s.resumed and (
-                        len(s.req.output) >= s.req.max_new_tokens
-                        or nxt == self.eos_id
-                        or s.pos >= self.max_len):
-                    s.req.done = True
-                    self.finished.append(s.req)
-                    self._lat.pop(s.req.rid, None)
-                    self.alloc.free_seq(self._seq_id(s))
-                    self.rows[i] = None
-                    finished += 1
+        with _obs.span("serve.sample"):
+            for i, s in pend:
+                s.pos += lens[i]
+                total += lens[i]
+                if s.pos == len(s.ctx):
+                    # prompt complete: first generated token comes from
+                    # the logits at the chunk's last real position (the
+                    # dense engine's argmax(prefill_logits[-1]) twin)
+                    nxt = self._to_host(jnp.argmax(row_logits[i]))
+                    s.req.output.append(nxt)
+                    s.prefilled = True
+                    lat = self._lat.get(s.req.rid)
+                    if lat is not None:
+                        if "last" not in lat:
+                            # first token ever for this request: TTFT
+                            self.metrics.record_latency(
+                                "ttft", tick - lat.get("submit", tick))
+                        else:
+                            # resumed prefill replays a decode tick: TPOT
+                            self.metrics.record_latency(
+                                "tpot", tick - lat["last"])
+                        lat["last"] = tick
+                    # a *resumed* prefill replays a decode tick, so its
+                    # token gets the decode-tick exhaustion check (fresh
+                    # admissions mirror the dense engine, which checks
+                    # only on decode)
+                    if s.resumed and (
+                            len(s.req.output) >= s.req.max_new_tokens
+                            or nxt == self.eos_id
+                            or s.pos >= self.max_len):
+                        s.req.done = True
+                        self.finished.append(s.req)
+                        self._lat.pop(s.req.rid, None)
+                        self.alloc.free_seq(self._seq_id(s))
+                        self.rows[i] = None
+                        finished += 1
         return {"prefill_tokens": total, "preempted": preempted,
                 "finished": finished,
                 "prefill_gather_bytes": gather_bytes,
@@ -551,7 +586,6 @@ class PagedServingEngine:
                 or getattr(model.cfg, "attn_type", None) == "mla":
             return None
         from repro.kernels.ragged_prefill.ops import verified_config
-        PS = self.page_size
         spans = [(i, s, s.pos, lens[i]) for i, s in pend]
         # pad both packed extents to 64-token granularity: bounds the
         # jit-recompile variety while keeping pow2 blocks available
@@ -567,14 +601,39 @@ class PagedServingEngine:
         if key not in self._prefill_cfgs:
             # ARGUS gate: verify the leakage invariants once per packed
             # geometry, config resolved from the dispatch table
-            self._prefill_cfgs[key] = verified_config(
-                TQp, TKp, len(spans), q_heads=mcfg.n_heads,
-                kv_heads=mcfg.n_kv_heads,
+            self._prefill_cfgs[key] = self._verify(
+                verified_config, TQp, TKp, len(spans),
+                q_heads=mcfg.n_heads, kv_heads=mcfg.n_kv_heads,
                 head_dim=mcfg.resolved_head_dim,
                 dtype="bf16" if "bf" in str(mcfg.dtype) else "f32")
         kcfg = self._prefill_cfgs[key]
         if kcfg is None:
             return None
+        with _obs.span("serve.pack"):
+            operands, q_last = self._pack(spans, TQp, TKp)
+        fn = self._prefill_fns.get(key)
+        with _obs.span("serve.launch"):
+            if fn is None:
+                interp = self._interpret
+
+                def serve_prefill_packed(prm, pool, tok, sq, pq, sk, pk,
+                                         wp, wo, gp, go):
+                    return model.prefill_chunk_packed(
+                        prm, pool, tok, sq, pq, sk, pk, wp, wo, gp, go,
+                        kernel_cfg=kcfg, interpret=interp)
+
+                fn = jax.jit(serve_prefill_packed)
+                self._prefill_fns[key] = fn
+            logits, self.kv.storage = fn(
+                self.params, self.kv.storage,
+                *(jnp.asarray(a) for a in operands))
+        return {i: logits[0, t] for i, t in q_last.items()}, TKp
+
+    def _pack(self, spans, TQp: int, TKp: int):
+        """The packed-prefill step's host operands for ``spans``, in its
+        argument order, and ``{row: packed index of its last query
+        token}``."""
+        PS = self.page_size
         tokens = np.zeros((1, TQp), np.int32)
         seg_q = np.full((TQp,), -1, np.int32)
         pos_q = np.zeros((TQp,), np.int32)
@@ -605,22 +664,8 @@ class PagedServingEngine:
             q_last[i] = qt + n - 1
             qt += n
             kt += p + n
-        fn = self._prefill_fns.get(key)
-        if fn is None:
-            interp = self._interpret
-            fn = jax.jit(
-                lambda prm, pool, tok, sq, pq, sk, pk, wp, wo, gp, go:
-                model.prefill_chunk_packed(prm, pool, tok, sq, pq, sk,
-                                           pk, wp, wo, gp, go,
-                                           kernel_cfg=kcfg,
-                                           interpret=interp))
-            self._prefill_fns[key] = fn
-        logits, self.kv.storage = fn(
-            self.params, self.kv.storage, jnp.asarray(tokens),
-            jnp.asarray(seg_q), jnp.asarray(pos_q), jnp.asarray(seg_k),
-            jnp.asarray(pos_k), jnp.asarray(wphys), jnp.asarray(woffs),
-            jnp.asarray(gphys), jnp.asarray(goffs))
-        return {i: logits[0, t] for i, t in q_last.items()}, TKp
+        return ((tokens, seg_q, pos_q, seg_k, pos_k, wphys, woffs, gphys,
+                 goffs), q_last)
 
     # -- decode --------------------------------------------------------------
     def _kernel_config(self, tables: np.ndarray):
@@ -639,8 +684,9 @@ class PagedServingEngine:
                 self._kernel_cfg = None
                 return None
             try:
-                self._kernel_cfg = validate_block_tables(
-                    tables, model=self.model, page_size=self.page_size,
+                self._kernel_cfg = self._verify(
+                    validate_block_tables, tables, model=self.model,
+                    page_size=self.page_size,
                     pool_pages=self.alloc.n_pages)
             except InvariantViolation:
                 self._kernel_cfg = None
@@ -651,34 +697,41 @@ class PagedServingEngine:
         K/V scatter happens inside ``decode_step_paged``; inactive rows
         carry null tables and length 0.  Returns logits, or None when no
         verified config exists for this geometry (gather fallback)."""
-        tables = self._tables()
-        cfg = self._kernel_config(tables)
-        if cfg is None:
-            return None
-        # kernel tables: only decoding rows expose their pages — a row
-        # mid-prefill holds pages for tokens not yet written, which the
-        # mapped-length consistency check (rightly) rejects
-        kt = np.zeros_like(tables)
-        lengths = np.zeros((self.max_batch,), np.int32)
-        for i, s in rows:
-            kt[i] = tables[i]
-            lengths[i] = s.pos + 1     # the token being written included
-        # hot-path concrete gate: range + mapped-length consistency (each
-        # row maps exactly ceil(length/page_size) pages, no null holes)
-        from repro.kernels.paged_attention.ops import validate_block_tables
-        validate_block_tables(kt, page_size=self.page_size,
-                              pool_pages=self.alloc.n_pages,
-                              lengths=lengths)
-        if self._kernel_fn is None:
-            kc, interp, model = cfg, self._interpret, self.model
-            self._kernel_fn = jax.jit(
-                lambda p, pool, t, tok, pos, lens:
-                model.decode_step_paged(p, pool, t, tok, pos, lens,
-                                        kernel_cfg=kc, interpret=interp))
-        logits, self.kv.storage = self._kernel_fn(
-            self.params, self.kv.storage, jnp.asarray(kt),
-            jnp.asarray(tokens), jnp.asarray(pos_vec),
-            jnp.asarray(lengths))
+        with _obs.span("serve.tables"):
+            tables = self._tables()
+            cfg = self._kernel_config(tables)
+            if cfg is None:
+                return None
+            # kernel tables: only decoding rows expose their pages — a
+            # row mid-prefill holds pages for tokens not yet written,
+            # which the mapped-length consistency check (rightly) rejects
+            kt = np.zeros_like(tables)
+            lengths = np.zeros((self.max_batch,), np.int32)
+            for i, s in rows:
+                kt[i] = tables[i]
+                lengths[i] = s.pos + 1   # the token being written included
+            # hot-path concrete gate: range + mapped-length consistency
+            # (each row maps exactly ceil(length/page_size) pages, no
+            # null holes)
+            from repro.kernels.paged_attention.ops import \
+                validate_block_tables
+            validate_block_tables(kt, page_size=self.page_size,
+                                  pool_pages=self.alloc.n_pages,
+                                  lengths=lengths)
+        with _obs.span("serve.launch"):
+            if self._kernel_fn is None:
+                kc, interp, model = cfg, self._interpret, self.model
+
+                def serve_decode_step(p, pool, t, tok, pos, lens):
+                    return model.decode_step_paged(
+                        p, pool, t, tok, pos, lens, kernel_cfg=kc,
+                        interpret=interp)
+
+                self._kernel_fn = jax.jit(serve_decode_step)
+            logits, self.kv.storage = self._kernel_fn(
+                self.params, self.kv.storage, jnp.asarray(kt),
+                jnp.asarray(tokens), jnp.asarray(pos_vec),
+                jnp.asarray(lengths))
         return logits
 
     def lower_kernel_steps(self) -> Dict[str, "jax.stages.Lowered"]:
@@ -725,35 +778,37 @@ class PagedServingEngine:
                   if self.decode_path == "kernel" else None)
         if logits is None:
             view = self._gather()
-            logits, view = self._decode(self.params, view,
-                                        jnp.asarray(tokens),
-                                        jnp.asarray(pos_vec))
-            self._scatter(view, {i: (s.pos, 1) for i, s in rows})
+            with _obs.span("serve.launch"):
+                logits, view = self._decode(self.params, view,
+                                            jnp.asarray(tokens),
+                                            jnp.asarray(pos_vec))
+                self._scatter(view, {i: (s.pos, 1) for i, s in rows})
             gather_bytes = self._view_bytes
         else:
             kernel_ticks = 1
         finished = 0
         tick = self.metrics.counters["ticks"]
-        for i, s in rows:
-            nxt = int(jnp.argmax(logits[i, -1]))
-            s.req.output.append(nxt)
-            s.pos += 1
-            s.ctx.append(int(tokens[i, 0]))
-            lat = self._lat.get(s.req.rid)
-            if lat is not None:
-                self.metrics.record_latency(
-                    "tpot", tick - lat.get("last", tick))
-                lat["last"] = tick
-            exhausted = (len(s.req.output) >= s.req.max_new_tokens
-                         or nxt == self.eos_id
-                         or s.pos >= self.max_len)
-            if exhausted:
-                s.req.done = True
-                self.finished.append(s.req)
-                self._lat.pop(s.req.rid, None)
-                self.alloc.free_seq(self._seq_id(s))
-                self.rows[i] = None
-                finished += 1
+        with _obs.span("serve.sample"):
+            for i, s in rows:
+                nxt = self._to_host(jnp.argmax(logits[i, -1]))
+                s.req.output.append(nxt)
+                s.pos += 1
+                s.ctx.append(int(tokens[i, 0]))
+                lat = self._lat.get(s.req.rid)
+                if lat is not None:
+                    self.metrics.record_latency(
+                        "tpot", tick - lat.get("last", tick))
+                    lat["last"] = tick
+                exhausted = (len(s.req.output) >= s.req.max_new_tokens
+                             or nxt == self.eos_id
+                             or s.pos >= self.max_len)
+                if exhausted:
+                    s.req.done = True
+                    self.finished.append(s.req)
+                    self._lat.pop(s.req.rid, None)
+                    self.alloc.free_seq(self._seq_id(s))
+                    self.rows[i] = None
+                    finished += 1
         return {"decode_tokens": len(rows), "finished": finished,
                 "preempted": preempted, "gather_bytes": gather_bytes,
                 "kernel_decode_ticks": kernel_ticks}
@@ -799,7 +854,10 @@ class PagedServingEngine:
             n_active = len(self.active)
             if _obs.enabled():
                 tick_sp.set(tick=self.metrics.counters["ticks"],
-                            active=n_active)
+                            active=n_active,
+                            decode_tokens=dec["decode_tokens"],
+                            prefill_tokens=pre["prefill_tokens"])
+            verify_us, self._verify_us = self._verify_us, 0
             self.metrics.record_tick(
                 queue_depth=len(self.queue), active=n_active,
                 occupancy=self.alloc.used_pages,
@@ -812,6 +870,8 @@ class PagedServingEngine:
                 kernel_decode_ticks=dec.get("kernel_decode_ticks", 0),
                 kernel_prefill_ticks=pre.get("kernel_prefill_ticks", 0),
                 prefill_gather_bytes=pre.get("prefill_gather_bytes", 0),
+                host_syncs=self._take_host_syncs(),
+                verify_us=verify_us,
                 step_time_us=int((self._clock() - t0) * 1e6))
         return n_active
 

@@ -21,10 +21,14 @@ injectable wall clock.  Schema v4 adds the prefill-path counters:
 ``kernel_prefill_ticks`` (prefill ticks served by the ragged-prefill
 kernel, no dense view) and ``prefill_gather_bytes`` (bytes the prefill
 path read from the pool — full dense views on the gather/fallback
-path, token-granular packed-KV reads on the kernel path).
-``from_snapshot`` still loads v2 and v3 snapshots (missing counters
-default to 0, latency defaults to empty on v2) and rejects unknown
-versions with a ``ValueError`` naming the version.
+path, token-granular packed-KV reads on the kernel path).  Schema v5
+adds the host-loop counters: ``host_syncs`` (device-to-host reads the
+engine blocked on, one per next-token readback) and ``verify_us``
+(microseconds the paged engine spent in ARGUS verification of batch
+and packed-prefill geometries it had not met, on its injectable clock).
+``from_snapshot`` still loads v2, v3 and v4 snapshots (missing
+counters default to 0, latency defaults to empty on v2) and rejects
+unknown versions with a ``ValueError`` naming the version.
 """
 from __future__ import annotations
 
@@ -32,13 +36,13 @@ from typing import Dict
 
 from repro.obs.hist import LogHistogram
 
-SCHEMA_VERSION = 4
+SCHEMA_VERSION = 5
 
 # The snapshot schema, by example.  docs/serving.md and
 # docs/observability.md embed this block verbatim (test_docs enforces
 # it) — update all together.
 SCHEMA_EXAMPLE = {
-    "schema": 4,
+    "schema": 5,
     "kind": "paged",            # "dense" | "paged"
     "capacity": 24,             # slots (dense) | usable pages (paged)
     "counters": {               # monotonic, cumulative
@@ -57,6 +61,10 @@ SCHEMA_EXAMPLE = {
         "prefill_gather_bytes": 2048,  # prefill-path pool reads: dense
                                        # views (gather/fallback) or
                                        # packed-KV tokens (kernel)
+        "host_syncs": 124,      # device-to-host next-token reads the
+                                # engine blocked on
+        "verify_us": 5200,      # ARGUS verification of new geometries,
+                                # microseconds on the engine's clock
     },
     "gauges": {                 # last recorded tick
         "queue_depth": 2,
@@ -84,9 +92,11 @@ SCHEMA_EXAMPLE = {
 _COUNTERS = ("ticks", "admitted", "finished", "preempted",
              "prefill_tokens", "decode_tokens", "gather_bytes",
              "kernel_decode_ticks", "kernel_prefill_ticks",
-             "prefill_gather_bytes")
-# counters new in schema v4: optional (default 0) when loading v2/v3
+             "prefill_gather_bytes", "host_syncs", "verify_us")
+# counters new in schema v4 and v5: optional (default 0) when loading
+# older snapshots
 _V4_COUNTERS = ("kernel_prefill_ticks", "prefill_gather_bytes")
+_V5_COUNTERS = ("host_syncs", "verify_us")
 _GAUGES = ("queue_depth", "active", "occupancy")
 _LATENCY = ("queue_wait", "ttft", "tpot", "step_time")
 
@@ -110,6 +120,7 @@ class ServingMetrics:
                     kernel_decode_ticks: int = 0,
                     kernel_prefill_ticks: int = 0,
                     prefill_gather_bytes: int = 0,
+                    host_syncs: int = 0, verify_us: int = 0,
                     step_time_us: int = 0) -> None:
         c = self.counters
         c["ticks"] += 1
@@ -122,6 +133,8 @@ class ServingMetrics:
         c["kernel_decode_ticks"] += kernel_decode_ticks
         c["kernel_prefill_ticks"] += kernel_prefill_ticks
         c["prefill_gather_bytes"] += prefill_gather_bytes
+        c["host_syncs"] += host_syncs
+        c["verify_us"] += verify_us
         self.latency["step_time"].record(step_time_us)
         g = {"queue_depth": int(queue_depth), "active": int(active),
              "occupancy": int(occupancy)}
@@ -164,7 +177,7 @@ class ServingMetrics:
     @classmethod
     def from_snapshot(cls, snap: Dict) -> "ServingMetrics":
         version = snap.get("schema")
-        if version not in (2, 3, SCHEMA_VERSION):
+        if version not in (2, 3, 4, SCHEMA_VERSION):
             raise ValueError(
                 f"unsupported metrics schema {version!r} "
                 f"(this build reads v2..v{SCHEMA_VERSION})")
@@ -172,11 +185,14 @@ class ServingMetrics:
         for group, keys in (("counters", _COUNTERS), ("gauges", _GAUGES),
                             ("peaks", _GAUGES)):
             src = snap[group]
-            # counters introduced by v4 are optional on older snapshots
-            # (default 0); nothing outside the schema is ever accepted
+            # counters introduced by v4 and v5 are optional on older
+            # snapshots (default 0); nothing outside the schema is ever
+            # accepted
             required = set(keys)
             if group == "counters" and version < 4:
                 required -= set(_V4_COUNTERS)
+            if group == "counters" and version < 5:
+                required -= set(_V5_COUNTERS)
             if not (required <= set(src) <= set(keys)):
                 raise ValueError(f"snapshot {group} keys {sorted(src)} != "
                                  f"schema keys {sorted(keys)}")

@@ -296,11 +296,109 @@ class TestTracer:
         gc.collect()
         before = sys.getallocatedblocks()
         for _ in range(100_000):
-            with span("hot"):
-                pass
+            # the serving engine's call sites of one tick
+            with span("serve.tick"):
+                with span("serve.tables"):
+                    pass
+                with span("serve.launch"):
+                    pass
+                with span("serve.sample"):
+                    pass
         delta = sys.getallocatedblocks() - before
         assert delta <= 16, \
             f"disabled span() allocated {delta} blocks over the loop"
+
+    def test_importing_obs_loads_no_jax(self):
+        code = ("import sys; sys.path.insert(0, sys.argv[1]); "
+                "import repro.obs; assert 'jax' not in sys.modules")
+        subprocess.run([sys.executable, "-c", code, SRC], check=True,
+                       timeout=60)
+
+
+# ---------------------------------------------------------------------------
+# Profiler sink: spans on the JAX profiler's trace, on the device clock
+# ---------------------------------------------------------------------------
+
+def _profile_events(trace_dir):
+    """Every event of the newest profile under ``trace_dir``, as
+    ``(name, start_ns, dur_ns, lane, stats)``."""
+    from jax.profiler import ProfileData
+    path = sorted(Path(trace_dir).rglob("*.xplane.pb"),
+                  key=lambda p: p.stat().st_mtime)[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                out.append((e.name, int(e.start_ns), int(e.duration_ns),
+                            f"{plane.name}/{line.name}",
+                            {k: v for k, v in e.stats}))
+    return out
+
+
+class TestProfilerSink:
+    def teardown_method(self):
+        obs.disable()
+
+    def test_scalar_attrs_become_annotation_metadata(self, tmp_path):
+        import jax
+        tr = obs.enable(clock=obs.TickClock())
+        with jax.profiler.trace(str(tmp_path)):
+            with obs.span("serve.tick", {"tick": 3}) as sp:
+                sp.set(decode_tokens=2, trace_ids=["req-1"])
+        obs.disable()
+        got = [e for e in _profile_events(tmp_path)
+               if e[0] == "serve.tick"]
+        assert len(got) == 1
+        assert got[0][4] == {"tick": 3, "decode_tokens": 2}
+        # the ring keeps every attr, as without the sink
+        assert tr.events()[0]["args"] == {"tick": 3, "decode_tokens": 2,
+                                          "trace_ids": ["req-1"]}
+
+    def test_engine_phases_nest_on_the_trace(self, tmp_path):
+        import jax
+        from repro import configs
+        from repro.models import build
+        from repro.serve import PagedServingEngine, Request
+        model = build(configs.get_reduced("qwen3-1.7b"))
+        params = model.init(jax.random.PRNGKey(0))
+        eng = PagedServingEngine(model, params, pool_pages=40, page_size=8,
+                                 max_batch=3, max_len=64, prefill_chunk=8,
+                                 eos_id=-1, decode_path="kernel",
+                                 prefill_path="kernel")
+        eng.submit(Request(0, list(range(2, 8)), max_new_tokens=6))
+        eng.step()
+        # two prompts packed together: a prefill geometry not met yet
+        eng.submit(Request(1, list(range(3, 9)), max_new_tokens=3))
+        eng.submit(Request(2, list(range(4, 9)), max_new_tokens=3))
+        with jax.profiler.trace(str(tmp_path)):
+            tr = obs.enable()
+            for _ in range(3):
+                eng.step()
+            obs.disable()
+        rows = [r for r in _profile_events(tmp_path)
+                if r[0].startswith("serve.")]
+        names = [r[0] for r in rows]
+        # the trace holds the engine's spans the ring holds
+        assert sorted(names) == sorted(e["name"] for e in tr.events()
+                                       if e["name"].startswith("serve."))
+        assert names.count("serve.tick") == 3
+        for want in ("serve.tables", "serve.launch", "serve.sample",
+                     "serve.pack", "serve.verify"):
+            assert want in names, names
+        evs = [{"name": n, "ts": s, "dur": d, "pid": 0, "tid": lane}
+               for n, s, d, lane, _ in rows]
+        assert obs.well_nested(evs)
+
+        def inside(a, b):
+            return b["ts"] <= a["ts"] and \
+                a["ts"] + a["dur"] <= b["ts"] + b["dur"]
+
+        by = lambda n: [e for e in evs if e["name"] == n]
+        chains = [(t, d, s) for t in by("serve.tick")
+                  for d in by("serve.decode_tick") if inside(d, t)
+                  for s in by("serve.sample") if inside(s, d)]
+        # every tick decoded: tick > decode_tick > sample, three times
+        assert len({id(t) for t, _, _ in chains}) == 3
 
 
 # ---------------------------------------------------------------------------
@@ -328,6 +426,15 @@ class TestPrometheus:
         assert 'argus_occupancy_peak{engine="paged"} 12' in text
         assert "# TYPE argus_ticks_total counter" in text
         assert "# TYPE argus_ttft histogram" in text
+
+    def test_host_loop_counters_are_exposed(self):
+        m = _sample_metrics()
+        m.record_tick(queue_depth=0, active=2, occupancy=12,
+                      decode_tokens=2, host_syncs=2, verify_us=930)
+        text = prometheus_text(m.snapshot())
+        assert "# TYPE argus_host_syncs_total counter" in text
+        assert 'argus_host_syncs_total{engine="paged"} 2' in text
+        assert 'argus_verify_us_total{engine="paged"} 930' in text
 
     def test_histogram_buckets_are_cumulative_and_end_at_inf(self):
         text = prometheus_text(_sample_metrics().snapshot())

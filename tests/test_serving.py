@@ -404,6 +404,92 @@ class TestKernelDecodePath:
         assert tight.metrics.counters["gather_bytes"] == 0
 
 
+@pytest.fixture
+def device_reads(monkeypatch):
+    """Counts the reads of a device array's value back to the host that
+    go through Python (``int``, ``float``, ``item``, ``device_get``, ...;
+    on the CPU ``np.asarray`` reads through the buffer protocol, which
+    this does not see): the blocking reads ``host_syncs`` must count."""
+    from jax._src.array import ArrayImpl
+    n = [0]
+    value, item = ArrayImpl._value, ArrayImpl.item
+
+    def counted_value(self):
+        n[0] += 1
+        return value.fget(self)
+
+    def counted_item(self, *args):
+        n[0] += 1
+        return item(self, *args)
+
+    monkeypatch.setattr(ArrayImpl, "_value", property(counted_value))
+    monkeypatch.setattr(ArrayImpl, "item", counted_item)
+    return n
+
+
+class TestHostLoopCounters:
+    """Schema v5's host-loop counters: ``host_syncs`` counts the device
+    reads the engine blocks on, at the read; ``verify_us`` the first
+    tick's verification of its geometry."""
+
+    def test_device_reads_are_counted_once_each(self, device_reads):
+        x = jnp.argmax(jnp.arange(5))
+        int(x), float(x), x.item(), jax.device_get(x)
+        assert device_reads[0] == 4
+
+    @pytest.mark.parametrize("path,pool_pages", [
+        ("gather", 40), ("kernel", 40), ("kernel", 9)])
+    def test_host_syncs_are_decode_tokens_plus_prompt_completions(
+            self, served, device_reads, path, pool_pages):
+        model, params = served
+        reqs = _mixed_requests(seed=11, n=4)
+        eng = PagedServingEngine(model, params, pool_pages=pool_pages,
+                                 page_size=8, max_batch=4, max_len=64,
+                                 prefill_chunk=8, eos_id=-1,
+                                 decode_path=path, prefill_path=path)
+        outs = _submit_all(eng, reqs)
+        c = eng.metrics.counters
+        assert c["host_syncs"] == device_reads[0] > 0
+        # today the engine reads each next token back on its own: one
+        # read per token emitted, preemption replays included
+        assert c["host_syncs"] == sum(len(o) for o in outs.values())
+        # the tight pool preempts: resumed prompts complete once more
+        assert (c["preempted"] > 0) == (pool_pages == 9)
+        if not c["preempted"]:
+            # every prompt completes once and yields one token
+            assert c["host_syncs"] == c["decode_tokens"] + len(reqs)
+
+    def test_dense_engine_counts_its_readbacks(self, served, device_reads):
+        model, params = served
+        reqs = _mixed_requests(seed=13, n=4)
+        eng = ServingEngine(model, params, n_slots=2, max_len=64, eos_id=-1)
+        outs = _submit_all(eng, reqs)
+        c = eng.metrics.counters
+        assert c["host_syncs"] == device_reads[0] \
+            == sum(len(o) for o in outs.values()) \
+            == c["decode_tokens"] + len(reqs)
+        assert c["verify_us"] == 0
+
+    @pytest.mark.parametrize("path", ["gather", "kernel"])
+    def test_first_tick_verifies_its_geometry(self, served, path):
+        from repro.obs import TickClock
+        model, params = served
+        clock = TickClock(step_us=50)
+        eng = PagedServingEngine(model, params, pool_pages=40, page_size=8,
+                                 max_batch=2, max_len=64, prefill_chunk=8,
+                                 eos_id=-1, decode_path=path,
+                                 prefill_path=path, clock=clock)
+        eng.submit(Request(0, list(range(2, 8)), max_new_tokens=4))
+        eng.step()                 # the whole prompt, then one decode
+        first = eng.metrics.counters["verify_us"]
+        # on a virtual clock each gate reads it twice: one step apart
+        assert first > 0 and first % 50 == 0
+        eng.step()
+        eng.step()
+        # the same geometries again: nothing new to verify
+        assert eng.metrics.counters["verify_us"] == first
+
+
 class TestRetirementBoundary:
     """Regression for the `pos >= max_len - 1` off-by-one: a sequence
     admitted at pos == max_len - 2 still owns the final writable cache
@@ -452,15 +538,17 @@ class TestMetricsSchema:
 
     def test_v2_snapshot_loads_with_empty_latency(self):
         """A pre-latency (schema 2) snapshot still loads — latency
-        defaults to empty histograms, the v4 prefill counters to 0 —
-        and re-snapshots at the current version."""
+        defaults to empty histograms, the v4 prefill counters and the
+        v5 host-loop counters to 0 — and re-snapshots at the current
+        version."""
         from repro.serve.metrics import SCHEMA_VERSION
         snap = self._v3_snapshot()
         v2 = {k: v for k, v in snap.items() if k != "latency"}
         v2["schema"] = 2
         v2["counters"] = {k: v for k, v in snap["counters"].items()
                           if k not in ("kernel_prefill_ticks",
-                                       "prefill_gather_bytes")}
+                                       "prefill_gather_bytes",
+                                       "host_syncs", "verify_us")}
         m = ServingMetrics.from_snapshot(v2)
         assert m.counters == snap["counters"]
         assert all(h.count == 0 for h in m.latency.values())
@@ -477,7 +565,8 @@ class TestMetricsSchema:
         v3 = dict(snap, schema=3)
         v3["counters"] = {k: v for k, v in snap["counters"].items()
                           if k not in ("kernel_prefill_ticks",
-                                       "prefill_gather_bytes")}
+                                       "prefill_gather_bytes",
+                                       "host_syncs", "verify_us")}
         m = ServingMetrics.from_snapshot(v3)
         assert m.counters["kernel_prefill_ticks"] == 0
         assert m.counters["prefill_gather_bytes"] == 0
@@ -488,9 +577,43 @@ class TestMetricsSchema:
 
     def test_unknown_versions_rejected_naming_the_version(self):
         snap = self._v3_snapshot()
-        for bad in (1, 5, 99, None):
+        for bad in (1, 6, 99, None):
             with pytest.raises(ValueError, match=f"schema {bad!r}"):
                 ServingMetrics.from_snapshot({**snap, "schema": bad})
+
+    def test_v4_snapshot_loads_without_host_loop_counters(self):
+        """A schema-4 snapshot predates ``host_syncs`` and
+        ``verify_us``: they load as 0, its prefill counters are kept,
+        and a v4 snapshot missing a v4 counter is still rejected."""
+        m = ServingMetrics(16, "paged")
+        m.record_tick(queue_depth=0, active=1, occupancy=3,
+                      prefill_tokens=8, kernel_prefill_ticks=1,
+                      prefill_gather_bytes=512, host_syncs=1,
+                      verify_us=70)
+        snap = m.snapshot()
+        v4 = dict(snap, schema=4)
+        v4["counters"] = {k: v for k, v in snap["counters"].items()
+                          if k not in ("host_syncs", "verify_us")}
+        got = ServingMetrics.from_snapshot(v4)
+        assert got.counters["host_syncs"] == 0
+        assert got.counters["verify_us"] == 0
+        assert got.counters["prefill_gather_bytes"] == 512
+        assert got.snapshot()["schema"] == 5
+        bad = dict(v4)
+        bad["counters"] = {k: v for k, v in v4["counters"].items()
+                           if k != "kernel_prefill_ticks"}
+        with pytest.raises(ValueError, match="counters keys"):
+            ServingMetrics.from_snapshot(bad)
+
+    def test_v5_round_trips_host_loop_counters(self):
+        m = ServingMetrics(16, "paged")
+        m.record_tick(queue_depth=0, active=2, occupancy=4,
+                      decode_tokens=2, host_syncs=2, verify_us=1234)
+        snap = m.snapshot()
+        assert snap["schema"] == 5
+        assert snap["counters"]["host_syncs"] == 2
+        assert snap["counters"]["verify_us"] == 1234
+        assert ServingMetrics.from_snapshot(snap).snapshot() == snap
 
     def test_v3_round_trips_latency_exactly(self):
         snap = self._v3_snapshot()
