@@ -102,8 +102,12 @@ class _Slot:
 
 class _HostReads:
     """The engines' one way to read a device value back to the host:
-    each read blocks until the device has computed it, and counts
-    toward the tick's ``host_syncs``."""
+    each read blocks until the device has computed it, and counts one
+    toward the tick's ``host_syncs``.  The dense engine reads each row's
+    next token on its own (``_to_host``); the paged engine reads a
+    sampling phase's next tokens, picked on the device, in one transfer
+    (``_to_host_array``): one read for the decode batch, one for the
+    prompts a prefill tick completes."""
 
     _host_syncs = 0
 
@@ -111,9 +115,32 @@ class _HostReads:
         self._host_syncs += 1
         return int(x)
 
+    def _to_host_array(self, x) -> np.ndarray:
+        self._host_syncs += 1
+        return jax.device_get(x)
+
     def _take_host_syncs(self) -> int:
         n, self._host_syncs = self._host_syncs, 0
         return n
+
+
+def greedy_tokens(logits: jnp.ndarray) -> jnp.ndarray:
+    """The greedy next token at every position of ``logits`` (..., V):
+    the first index of the largest logit, ``jnp.argmax``'s rule and the
+    dense engine's.  Traced inside the paged engine's jitted steps, so a
+    tick's tokens leave the device as one int32 array, not as logits."""
+    return jnp.argmax(logits, axis=-1)
+
+
+def _greedy_step(step):
+    """``step`` (params, cache, tokens (B, S), pos) -> (logits (B, S, V),
+    cache) returning the greedy tokens (B, S) in place of the logits."""
+
+    def greedy_step(params, cache, tokens, pos):
+        logits, cache = step(params, cache, tokens, pos)
+        return greedy_tokens(logits), cache
+
+    return greedy_step
 
 
 class ServingEngine(_HostReads):
@@ -314,8 +341,9 @@ class PagedServingEngine(_HostReads):
         self.finished: List[Request] = []
         self.metrics = ServingMetrics(capacity=self.alloc.usable_pages,
                                       kind="paged")
-        self._decode = jax.jit(model.decode_step)
-        self._chunk = (jax.jit(model.decode_chunk)
+        # the gather paths' steps: greedy tokens (B, S), not logits
+        self._decode = jax.jit(_greedy_step(model.decode_step))
+        self._chunk = (jax.jit(_greedy_step(model.decode_chunk))
                        if hasattr(model, "decode_chunk") else None)
         self._clock = clock or time.perf_counter
         self._lat: Dict[int, Dict[str, int]] = {}   # rid -> tick stamps
@@ -509,7 +537,7 @@ class PagedServingEngine(_HostReads):
         packed = (self._prefill_kernel(pend, lens)
                   if self.prefill_path == "kernel" else None)
         if packed is not None:
-            row_logits, packed_kv_tokens = packed
+            next_tokens, at, packed_kv_tokens = packed
             gather_bytes = packed_kv_tokens * self._token_bytes
             kernel_ticks = 1
         else:
@@ -523,23 +551,29 @@ class PagedServingEngine(_HostReads):
             view = self._gather()
             fn = self._chunk if self._chunk is not None else self._decode
             with _obs.span("serve.launch"):
-                logits, view = fn(self.params, view, jnp.asarray(tokens),
-                                  jnp.asarray(pos_vec))
+                next_tokens, view = fn(self.params, view,
+                                       jnp.asarray(tokens),
+                                       jnp.asarray(pos_vec))
                 self._scatter(view, {i: (s.pos, lens[i]) for i, s in pend})
-            row_logits = {i: logits[i, lens[i] - 1] for i, s in pend}
+            at = {i: (i, lens[i] - 1) for i, s in pend}
             gather_bytes = self._view_bytes
         total = 0
         finished = 0
         tick = self.metrics.counters["ticks"]
         with _obs.span("serve.sample"):
+            # one read for every prompt this tick completes, none when
+            # no prompt completes
+            host_tokens = (self._to_host_array(next_tokens)
+                           if any(s.pos + lens[i] == len(s.ctx)
+                                  for i, s in pend) else None)
             for i, s in pend:
                 s.pos += lens[i]
                 total += lens[i]
                 if s.pos == len(s.ctx):
-                    # prompt complete: first generated token comes from
-                    # the logits at the chunk's last real position (the
+                    # prompt complete: first generated token is the
+                    # greedy pick at the chunk's last real position (the
                     # dense engine's argmax(prefill_logits[-1]) twin)
-                    nxt = self._to_host(jnp.argmax(row_logits[i]))
+                    nxt = int(host_tokens[at[i]])
                     s.req.output.append(nxt)
                     s.prefilled = True
                     lat = self._lat.get(s.req.rid)
@@ -576,10 +610,11 @@ class PagedServingEngine(_HostReads):
         """Kernel-path chunked prefill: pack the tick's prompt chunks
         ragged and attend them through the ragged-prefill kernel
         straight off the pool (token-granular packed-KV gather, no
-        dense view).  Returns ``({row: last-real-token logits}, packed
-        kv tokens)``, or None when the model cannot packed-prefill
-        (MLA / no hook) or the packed geometry has no verified config —
-        the tick then falls back to the dense ``decode_chunk`` path."""
+        dense view).  Returns ``(greedy tokens (1, TQp) on the device,
+        {row: index of its last real token in them}, packed kv
+        tokens)``, or None when the model cannot packed-prefill (MLA / no
+        hook) or the packed geometry has no verified config — the tick
+        then falls back to the dense ``decode_chunk`` path."""
         model = self.model
         if self._chunk is None \
                 or not hasattr(model, "prefill_chunk_packed") \
@@ -618,16 +653,17 @@ class PagedServingEngine(_HostReads):
 
                 def serve_prefill_packed(prm, pool, tok, sq, pq, sk, pk,
                                          wp, wo, gp, go):
-                    return model.prefill_chunk_packed(
+                    logits, pool = model.prefill_chunk_packed(
                         prm, pool, tok, sq, pq, sk, pk, wp, wo, gp, go,
                         kernel_cfg=kcfg, interpret=interp)
+                    return greedy_tokens(logits), pool
 
                 fn = jax.jit(serve_prefill_packed)
                 self._prefill_fns[key] = fn
-            logits, self.kv.storage = fn(
+            next_tokens, self.kv.storage = fn(
                 self.params, self.kv.storage,
                 *(jnp.asarray(a) for a in operands))
-        return {i: logits[0, t] for i, t in q_last.items()}, TKp
+        return next_tokens, {i: (0, t) for i, t in q_last.items()}, TKp
 
     def _pack(self, spans, TQp: int, TKp: int):
         """The packed-prefill step's host operands for ``spans``, in its
@@ -695,8 +731,9 @@ class PagedServingEngine(_HostReads):
     def _decode_kernel(self, rows, tokens, pos_vec):
         """Kernel-path decode tick: no gather, no dense view.  The fresh
         K/V scatter happens inside ``decode_step_paged``; inactive rows
-        carry null tables and length 0.  Returns logits, or None when no
-        verified config exists for this geometry (gather fallback)."""
+        carry null tables and length 0.  Returns the greedy tokens (B, 1)
+        on the device, or None when no verified config exists for this
+        geometry (gather fallback)."""
         with _obs.span("serve.tables"):
             tables = self._tables()
             cfg = self._kernel_config(tables)
@@ -723,16 +760,17 @@ class PagedServingEngine(_HostReads):
                 kc, interp, model = cfg, self._interpret, self.model
 
                 def serve_decode_step(p, pool, t, tok, pos, lens):
-                    return model.decode_step_paged(
+                    logits, pool = model.decode_step_paged(
                         p, pool, t, tok, pos, lens, kernel_cfg=kc,
                         interpret=interp)
+                    return greedy_tokens(logits), pool
 
                 self._kernel_fn = jax.jit(serve_decode_step)
-            logits, self.kv.storage = self._kernel_fn(
+            next_tokens, self.kv.storage = self._kernel_fn(
                 self.params, self.kv.storage, jnp.asarray(kt),
                 jnp.asarray(tokens), jnp.asarray(pos_vec),
                 jnp.asarray(lengths))
-        return logits
+        return next_tokens
 
     def lower_kernel_steps(self) -> Dict[str, "jax.stages.Lowered"]:
         """Lower every kernel-path step this engine has built, at the
@@ -774,14 +812,14 @@ class PagedServingEngine(_HostReads):
             tokens[i, 0] = s.req.output[-1]
             pos_vec[i] = s.pos
         gather_bytes = kernel_ticks = 0
-        logits = (self._decode_kernel(rows, tokens, pos_vec)
-                  if self.decode_path == "kernel" else None)
-        if logits is None:
+        next_tokens = (self._decode_kernel(rows, tokens, pos_vec)
+                       if self.decode_path == "kernel" else None)
+        if next_tokens is None:
             view = self._gather()
             with _obs.span("serve.launch"):
-                logits, view = self._decode(self.params, view,
-                                            jnp.asarray(tokens),
-                                            jnp.asarray(pos_vec))
+                next_tokens, view = self._decode(self.params, view,
+                                                 jnp.asarray(tokens),
+                                                 jnp.asarray(pos_vec))
                 self._scatter(view, {i: (s.pos, 1) for i, s in rows})
             gather_bytes = self._view_bytes
         else:
@@ -789,8 +827,10 @@ class PagedServingEngine(_HostReads):
         finished = 0
         tick = self.metrics.counters["ticks"]
         with _obs.span("serve.sample"):
+            # the whole batch's next tokens in one read
+            host_tokens = self._to_host_array(next_tokens)
             for i, s in rows:
-                nxt = self._to_host(jnp.argmax(logits[i, -1]))
+                nxt = int(host_tokens[i, 0])
                 s.req.output.append(nxt)
                 s.pos += 1
                 s.ctx.append(int(tokens[i, 0]))

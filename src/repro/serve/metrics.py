@@ -23,9 +23,10 @@ kernel, no dense view) and ``prefill_gather_bytes`` (bytes the prefill
 path read from the pool — full dense views on the gather/fallback
 path, token-granular packed-KV reads on the kernel path).  Schema v5
 adds the host-loop counters: ``host_syncs`` (device-to-host reads the
-engine blocked on, one per next-token readback) and ``verify_us``
-(microseconds the paged engine spent in ARGUS verification of batch
-and packed-prefill geometries it had not met, on its injectable clock).
+engine blocked on; the paged engine makes one per sampling phase) and
+``verify_us`` (microseconds the paged engine spent in ARGUS
+verification of batch and packed-prefill geometries it had not met,
+on its injectable clock).
 ``from_snapshot`` still loads v2, v3 and v4 snapshots (missing
 counters default to 0, latency defaults to empty on v2) and rejects
 unknown versions with a ``ValueError`` naming the version.

@@ -441,23 +441,44 @@ class TestHostLoopCounters:
         ("gather", 40), ("kernel", 40), ("kernel", 9)])
     def test_host_syncs_are_decode_tokens_plus_prompt_completions(
             self, served, device_reads, path, pool_pages):
+        """The next tokens are picked on the device and read back once
+        per sampling phase: one read for the decode batch, one for the
+        prompts a tick completes, whatever the number of rows."""
         model, params = served
         reqs = _mixed_requests(seed=11, n=4)
+        dense = _submit_all(ServingEngine(model, params, n_slots=4,
+                                          max_len=64, eos_id=-1), reqs)
+        reads0 = device_reads[0]
         eng = PagedServingEngine(model, params, pool_pages=pool_pages,
                                  page_size=8, max_batch=4, max_len=64,
                                  prefill_chunk=8, eos_id=-1,
                                  decode_path=path, prefill_path=path)
-        outs = _submit_all(eng, reqs)
+        for rid, (p, m) in enumerate(reqs):
+            eng.submit(Request(rid, list(p), max_new_tokens=m))
+        reqs_by_rid = {r.rid: r for r in eng.queue}
+        emitted = lambda: sum(len(r.output) for r in reqs_by_rid.values())
+        per_tick = []
+        while eng.queue or eng.active:
+            c0, e0 = dict(eng.metrics.counters), emitted()
+            eng.step()
+            c = eng.metrics.counters
+            decoded = c["decode_tokens"] - c0["decode_tokens"]
+            # every token a tick emits is a decode row's or a prompt's
+            completed = emitted() - e0 - decoded
+            per_tick.append((c["host_syncs"] - c0["host_syncs"],
+                             decoded, completed))
         c = eng.metrics.counters
-        assert c["host_syncs"] == device_reads[0] > 0
-        # today the engine reads each next token back on its own: one
-        # read per token emitted, preemption replays included
-        assert c["host_syncs"] == sum(len(o) for o in outs.values())
+        assert c["host_syncs"] == device_reads[0] - reads0 > 0
+        for syncs, decoded, completed in per_tick:
+            assert syncs == (decoded > 0) + (completed > 0)
+            assert syncs <= 2 and (syncs <= 1 or completed)
+        # several rows decode on one tick, yet each tick reads once:
+        # fewer reads than tokens emitted (one each before)
+        assert max(d for _, d, _ in per_tick) > 1
+        assert c["host_syncs"] < sum(d + k for _, d, k in per_tick)
         # the tight pool preempts: resumed prompts complete once more
         assert (c["preempted"] > 0) == (pool_pages == 9)
-        if not c["preempted"]:
-            # every prompt completes once and yields one token
-            assert c["host_syncs"] == c["decode_tokens"] + len(reqs)
+        assert {r.rid: r.output for r in eng.finished} == dense
 
     def test_dense_engine_counts_its_readbacks(self, served, device_reads):
         model, params = served
@@ -488,6 +509,117 @@ class TestHostLoopCounters:
         eng.step()
         # the same geometries again: nothing new to verify
         assert eng.metrics.counters["verify_us"] == first
+
+
+def _compile_counter():
+    """Counts XLA compiles and persistent-cache loads while registered,
+    as the benchmark's ``window_compiles`` does (``jax.monitoring``)."""
+    n = [0]
+
+    def on_duration(event, secs, **_):
+        n[0] += event == "/jax/core/compile/backend_compile_duration"
+
+    def on_event(event, **_):
+        n[0] += event == "/jax/compilation_cache/cache_hits"
+
+    return n, on_duration, on_event
+
+
+class TestDeviceGreedy:
+    """The paged engine picks each tick's next tokens inside its jitted
+    steps and reads them back in one transfer per sampling phase."""
+
+    @pytest.mark.parametrize("case", ["decode", "packed_prefill"])
+    def test_batched_greedy_matches_per_row_argmax(self, case):
+        from repro.serve.engine import greedy_tokens
+        rng = np.random.default_rng(7)
+        V = 37
+        if case == "decode":
+            # (B, 1, V): row i's token at [i, 0]
+            logits = rng.integers(0, 3, size=(6, 1, V)).astype(np.float32)
+            picks = [(i, 0) for i in range(6)]
+        else:
+            # (1, TQ, V): chunks of 3, 1, 5 and 2 tokens packed and
+            # padded to 16; row i's token at its last query, q_last
+            logits = rng.integers(0, 3, size=(1, 16, V)).astype(np.float32)
+            picks = [(0, int(t)) for t in np.cumsum([3, 1, 5, 2]) - 1]
+        # ties everywhere (integer logits in [0, 3)), plus a flat row and
+        # a row whose maximum its last two entries share
+        flat, pair = picks[0], picks[-1]
+        logits[flat] = 1.0
+        logits[pair] = 0.0
+        logits[pair][-2:] = 2.0
+        got = np.asarray(jax.jit(greedy_tokens)(jnp.asarray(logits)))
+        assert got.dtype == np.int32 and got.shape == logits.shape[:-1]
+        for at in picks:
+            assert got[at] == int(jnp.argmax(jnp.asarray(logits[at])))
+        assert got[flat] == 0 and got[pair] == V - 2
+
+    def test_full_batch_and_two_completions_read_back_twice(
+            self, served, device_reads):
+        """A kernel-path tick on which every row decodes and two prompts
+        complete reads the device twice: once for the completed prompts,
+        once for the batch; the tokens are the dense engine's."""
+        model, params = served
+        reqs = [(list(range(2 + k, 9 + k)), 10) for k in range(4)]
+        dense = _submit_all(ServingEngine(model, params, n_slots=4,
+                                          max_len=64, eos_id=-1), reqs)
+        eng = PagedServingEngine(model, params, pool_pages=40, page_size=8,
+                                 max_batch=4, max_len=64, prefill_chunk=8,
+                                 eos_id=-1, decode_path="kernel",
+                                 prefill_path="kernel")
+        for rid, (p, m) in enumerate(reqs[:2]):
+            eng.submit(Request(rid, list(p), max_new_tokens=m))
+        eng.step()
+        for rid, (p, m) in enumerate(reqs[2:], start=2):
+            eng.submit(Request(rid, list(p), max_new_tokens=m))
+        c0, reads0 = dict(eng.metrics.counters), device_reads[0]
+        eng.step()
+        c = eng.metrics.counters
+        assert c["decode_tokens"] - c0["decode_tokens"] == eng.max_batch
+        assert c["prefill_tokens"] - c0["prefill_tokens"] == 14
+        assert c["kernel_decode_ticks"] - c0["kernel_decode_ticks"] == 1
+        assert c["kernel_prefill_ticks"] - c0["kernel_prefill_ticks"] == 1
+        assert c["host_syncs"] - c0["host_syncs"] == 2
+        assert device_reads[0] - reads0 == 2
+        assert {r.rid: r.output for r in eng.run()} == dense
+
+    def test_built_steps_compile_nothing_more(self, served):
+        """Greedy selection lives inside the jitted steps: once a
+        geometry's steps are built, ticks at it compile nothing."""
+        model, params = served
+        reqs = _mixed_requests(seed=11, n=4)
+        eng = PagedServingEngine(model, params, pool_pages=40, page_size=8,
+                                 max_batch=4, max_len=64, prefill_chunk=8,
+                                 eos_id=-1, decode_path="kernel",
+                                 prefill_path="kernel")
+        first = _submit_all(eng, reqs)
+        n, on_duration, on_event = _compile_counter()
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(on_event)
+        try:
+            again = _submit_all(eng, [(p, m) for p, m in reqs])
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_duration)
+            jax.monitoring.unregister_event_listener(on_event)
+        assert eng.metrics.counters["kernel_prefill_ticks"] > 0
+        assert n[0] == 0
+        assert again == first
+
+    def test_kernel_steps_return_tokens_not_logits(self, served):
+        model, params = served
+        eng = PagedServingEngine(model, params, pool_pages=40, page_size=8,
+                                 max_batch=2, max_len=64, prefill_chunk=8,
+                                 eos_id=-1, decode_path="kernel",
+                                 prefill_path="kernel")
+        eng.submit(Request(0, list(range(2, 8)), max_new_tokens=2))
+        eng.step()
+        steps = eng.lower_kernel_steps()
+        assert sorted(steps) == ["decode", "prefill_64x64_1"]
+        want = {"decode": (2, 1), "prefill_64x64_1": (1, 64)}
+        for name, lowered in steps.items():
+            tokens, _ = lowered.out_info
+            assert (tokens.shape, tokens.dtype) == (want[name], jnp.int32)
 
 
 class TestRetirementBoundary:
